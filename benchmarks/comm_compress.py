@@ -134,7 +134,9 @@ def _measure_dist(steps: int):
                 "final_loss": float(m["loss"])}
         print("RESULT " + json.dumps(out))
     """ % (steps, list(CODECS)))
-    env = dict(os.environ)
+    # eight virtual CPU devices: the child never reaches for an accelerator
+    # (this process may already hold it)
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     env["PYTHONPATH"] = os.path.join(REPO, "src")
     r = subprocess.run([sys.executable, "-c", code], capture_output=True,

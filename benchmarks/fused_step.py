@@ -141,7 +141,9 @@ def _measure_dist(steps: int):
             out["stacked_param_bytes"] = tr.comm_cost().bytes_per_event * W
         print("RESULT " + json.dumps(out))
     """ % steps)
-    env = dict(os.environ)
+    # eight virtual CPU devices: the child never reaches for an accelerator
+    # (this process may already hold it)
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     env["PYTHONPATH"] = os.path.join(REPO, "src")
     r = subprocess.run([sys.executable, "-c", code], capture_output=True,

@@ -15,6 +15,7 @@ from benchmarks import (alpha_schedule, comm_compress, comm_cost, faults,
                         fleet, fused_step, obs, roofline_bench, serve_live,
                         shard, straggler, table_4_1, table_4_2, table_4_3,
                         table_a_1)
+from repro.common.cache import enable_compile_cache
 
 TABLES = {
     "table_4_1": table_4_1.main,
@@ -41,6 +42,7 @@ def main() -> None:
     ap.add_argument("--full", action="store_true")
     ap.add_argument("--only", default="")
     args = ap.parse_args()
+    enable_compile_cache()
     only = set(args.only.split(",")) if args.only else set(TABLES)
     t0 = time.time()
     for name, fn in TABLES.items():

@@ -39,7 +39,6 @@ from jax.sharding import Mesh, PartitionSpec as P
 
 from repro import comm
 from repro.api import registry
-from repro.common import compat
 from repro.common import flat as flat_plane
 from repro.common.config import MeshConfig, ProtocolConfig
 from repro.core import topology
@@ -140,7 +139,7 @@ def make_gossip_step(mesh: Mesh, mesh_cfg: MeshConfig, cfg: ProtocolConfig,
     # replica onto each chip before the concat (and a pallas_call has no
     # GSPMD sharding rule at all). Manual shards keep the exchange moving
     # shard-local bytes only.
-    manual = set(mesh.axis_names)
+    manual = frozenset(mesh.axis_names)
 
     sharded = shard is not None and shard.enabled()
     if sharded:
@@ -268,27 +267,27 @@ def make_gossip_step(mesh: Mesh, mesh_cfg: MeshConfig, cfg: ProtocolConfig,
             @jax.jit
             def gossip_step(params_stack, velocity, grads, residual, active,
                             round_idx, eta, mu):
-                fn = compat.shard_map(
+                fn = jax.shard_map(
                     lambda p, v, g, r, a, e, m: local_fused(p, v, g, r, a[0],
                                                             round_idx, e, m),
-                    mesh,
+                    mesh=mesh,
                     in_specs=(param_specs, param_specs, param_specs, param_specs,
                               active_spec, P(), P()),
                     out_specs=(param_specs, param_specs, param_specs),
-                    manual_axes=manual,
+                    axis_names=manual, check_vma=False,
                 )
                 return fn(params_stack, velocity, grads, residual, active, eta, mu)
         else:
             @jax.jit
             def gossip_step(params_stack, velocity, grads, active, round_idx, eta, mu):
-                fn = compat.shard_map(
+                fn = jax.shard_map(
                     lambda p, v, g, a, e, m: local_fused(p, v, g, None, a[0],
                                                          round_idx, e, m),
-                    mesh,
+                    mesh=mesh,
                     in_specs=(param_specs, param_specs, param_specs, active_spec,
                               P(), P()),
                     out_specs=(param_specs, param_specs),
-                    manual_axes=manual,
+                    axis_names=manual, check_vma=False,
                 )
                 return fn(params_stack, velocity, grads, active, eta, mu)
     elif stateful:
@@ -297,12 +296,12 @@ def make_gossip_step(mesh: Mesh, mesh_cfg: MeshConfig, cfg: ProtocolConfig,
 
         @jax.jit
         def gossip_step(params_stack, residual, active, round_idx):
-            fn = compat.shard_map(
+            fn = jax.shard_map(
                 lambda p, r, a: local_update(p, r, a[0], round_idx),
-                mesh,
+                mesh=mesh,
                 in_specs=(param_specs, param_specs, active_spec),
                 out_specs=out_specs,
-                manual_axes=manual,
+                axis_names=manual, check_vma=False,
             )
             return fn(params_stack, residual, active)
     else:
@@ -310,12 +309,12 @@ def make_gossip_step(mesh: Mesh, mesh_cfg: MeshConfig, cfg: ProtocolConfig,
 
         @jax.jit
         def gossip_step(params_stack, active, round_idx):
-            fn = compat.shard_map(
+            fn = jax.shard_map(
                 lambda p, a: local_update(p, None, a[0], round_idx),
-                mesh,
+                mesh=mesh,
                 in_specs=(param_specs, active_spec),
                 out_specs=out_specs,
-                manual_axes=manual,
+                axis_names=manual, check_vma=False,
             )
             return fn(params_stack, active)
 

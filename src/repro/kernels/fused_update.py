@@ -7,20 +7,23 @@ pass: read theta/peer/v/g once, write theta'/v' once (6 streams total), at
 arithmetic intensity ~0.5 flop/byte — pure bandwidth, so fusion is the whole
 game (byte accounting: benchmarks/fused_step.py).
 
-Tiling: params are flattened and padded to 1-D tiles of ``block`` elements
-(default 65536 = 256 KiB f32 per stream; 6 streams -> 1.5 MiB VMEM working
-set, lane-aligned multiples of 128). The dynamic participation gate is folded
-into coef on the host, so the kernel body is branch-free.
+Tiling (:func:`plane_call`): a ``[W, N]`` plane is cut into column tiles of a
+lane-multiple width chosen by :func:`_tile`. With several replicas a tile is
+``(rows, cols)`` with ``rows`` the whole replica dim (8 of it when W is a
+multiple of 8 above 8) — the tiling XLA itself gives a ``[W, N]`` f32 array
+on the TPU (``T(W,128)``), so the kernel reads the resident buffer with no
+relayout copy. Per-replica scalars (coef, eta, mu) ride
+in a small ``[W, k]`` f32 operand whose block is the tile's rows, so traced
+values never retrigger compilation and the kernel body is branch-free.
 
 Two entry points:
 
-- :func:`fused_elastic_nag_update` — single array, static eta/mu (the
-  original per-leaf kernel, kept for the oracle tests);
 - :func:`fused_flat_elastic_nag_update` / :func:`fused_flat_nag_update` —
   ``[W, N]`` flat replica buffers from :mod:`repro.common.flat`, with
-  per-replica coef and *traced* eta/mu packed into a small scalar operand, so
-  one compiled program serves every step of an lr schedule. These are what
-  the engines call (through :mod:`repro.kernels.ops`).
+  per-replica coef and traced eta/mu. These are what the engines call
+  (through :mod:`repro.kernels.ops`).
+- :func:`fused_elastic_nag_update` — any single array, seen as a one-row
+  plane (kept for the oracle tests).
 """
 from __future__ import annotations
 
@@ -30,85 +33,11 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-BLOCK = 65536  # elements per tile; multiple of 128*8 for lane/sublane alignment
-
-
-def _kernel(theta_ref, peer_ref, v_ref, g_ref, coef_ref,
-            theta_out_ref, v_out_ref, *, eta: float, mu: float):
-    t = theta_ref[...].astype(jnp.float32)
-    p = peer_ref[...].astype(jnp.float32)
-    v = v_ref[...].astype(jnp.float32)
-    g = g_ref[...].astype(jnp.float32)
-    coef = coef_ref[0, 0]
-    v_new = mu * v - eta * g
-    t_new = t - coef * (t - p) - eta * g + mu * v_new
-    theta_out_ref[...] = t_new.astype(theta_out_ref.dtype)
-    v_out_ref[...] = v_new.astype(v_out_ref.dtype)
-
-
-@functools.partial(jax.jit, static_argnames=("eta", "mu", "block", "interpret"))
-def fused_elastic_nag_update(theta, peer, v, g, coef_gate, *, eta: float, mu: float,
-                             block: int = BLOCK, interpret: bool = False):
-    """theta/peer/v/g: same-shape arrays (any rank); coef_gate: scalar f32
-    (= alpha * participation gate). Returns (theta', v')."""
-    shape, dtype = theta.shape, theta.dtype
-    n = theta.size
-    nblocks = max(1, (n + block - 1) // block)
-    pad = nblocks * block - n
-
-    def prep(x):
-        flat = x.reshape(-1)
-        if pad:
-            flat = jnp.pad(flat, (0, pad))
-        return flat.reshape(nblocks, block)
-
-    tf, pf, vf, gf = prep(theta), prep(peer), prep(v), prep(g)
-    coef = jnp.asarray(coef_gate, jnp.float32).reshape(1, 1)
-
-    spec = pl.BlockSpec((1, block), lambda i: (i, 0))
-    coef_spec = pl.BlockSpec((1, 1), lambda i: (0, 0))
-    t_new, v_new = pl.pallas_call(
-        functools.partial(_kernel, eta=eta, mu=mu),
-        grid=(nblocks,),
-        in_specs=[spec, spec, spec, spec, coef_spec],
-        out_specs=[spec, spec],
-        out_shape=[jax.ShapeDtypeStruct((nblocks, block), dtype),
-                   jax.ShapeDtypeStruct((nblocks, block), v.dtype)],
-        interpret=interpret,
-    )(tf, pf, vf, gf, coef)
-    return (t_new.reshape(-1)[:n].reshape(shape),
-            v_new.reshape(-1)[:n].reshape(shape))
-
-
-# ---------------------------------------------------------------------------
-# Flat-plane kernels: [W, N] replica buffers, runtime scalars
-# ---------------------------------------------------------------------------
-
-def _flat_kernel(theta_ref, peer_ref, v_ref, g_ref, sc_ref,
-                 theta_out_ref, v_out_ref):
-    t = theta_ref[...].astype(jnp.float32)
-    p = peer_ref[...].astype(jnp.float32)
-    v = v_ref[...].astype(jnp.float32)
-    g = g_ref[...].astype(jnp.float32)
-    coef, eta, mu = sc_ref[0, 0], sc_ref[0, 1], sc_ref[0, 2]
-    v_new = mu * v - eta * g
-    t_new = t - coef * (t - p) - eta * g + mu * v_new
-    theta_out_ref[...] = t_new.astype(theta_out_ref.dtype)
-    v_out_ref[...] = v_new.astype(v_out_ref.dtype)
-
-
-def _flat_nag_kernel(theta_ref, v_ref, g_ref, sc_ref, theta_out_ref, v_out_ref):
-    t = theta_ref[...].astype(jnp.float32)
-    v = v_ref[...].astype(jnp.float32)
-    g = g_ref[...].astype(jnp.float32)
-    eta, mu = sc_ref[0, 0], sc_ref[0, 1]
-    v_new = mu * v - eta * g
-    theta_out_ref[...] = (t - eta * g + mu * v_new).astype(theta_out_ref.dtype)
-    v_out_ref[...] = v_new.astype(v_out_ref.dtype)
-
-
-LANE = 128        # lane width (elements): flat-plane totals are multiples of it
-MIN_TILE = 8 * LANE   # one full f32 (sublane x lane) register tile
+BLOCK = 65536          # max elements per replica row in one tile
+LANE = 128             # lane width (elements): flat-plane totals are multiples of it
+SUBLANE = 8            # f32 sublanes per vreg
+MIN_TILE = SUBLANE * LANE   # one full f32 (sublane x lane) register tile
+TILE_BYTES = 1 << 20   # VMEM one stream's tile may take: 6 streams x 2 buffers < 16 MiB
 
 
 def _tile(n: int, block: int):
@@ -138,6 +67,18 @@ def _tile(n: int, block: int):
     return block, (n + block - 1) // block, True
 
 
+def tile_rows(W: int) -> int:
+    """Replica rows per tile: all of them, or 8 when W is a multiple of 8."""
+    return SUBLANE if W > SUBLANE and W % SUBLANE == 0 else W
+
+
+def tile_cols_cap(rows: int) -> int:
+    """Widest lane-multiple column tile whose f32 VMEM buffer (rows padded
+    to whole sublane groups) stays within ``TILE_BYTES``."""
+    sub = -(-rows // SUBLANE) * SUBLANE
+    return max(LANE, TILE_BYTES // (4 * sub) // LANE * LANE)
+
+
 def _pad_blocks(x, n: int, nblocks: int, block: int):
     pad = nblocks * block - n
     return jnp.pad(x, ((0, 0), (0, pad))) if pad else x
@@ -150,6 +91,62 @@ def _scalar_rows(W: int, *cols) -> jnp.ndarray:
     return jnp.stack(rows, axis=1)
 
 
+def plane_call(kernel, planes, scalars, out_from, *, block: int,
+               interpret: bool):
+    """Run an elementwise ``kernel`` over same-shape ``[W, N]`` planes.
+
+    ``kernel(*plane_refs, sc_ref, *out_refs)`` sees one tile of every plane
+    and the tile's rows of the ``[W, len(scalars)]`` f32 scalar operand
+    (each scalar a python/traced value or a ``[W]`` vector; column ``c`` of
+    ``sc_ref[...]`` is scalar ``c`` as a per-row column). Output ``o`` has
+    the shape and dtype of ``planes[out_from[o]]`` and aliases it whenever
+    the tiling covers N exactly. Returns the list of ``[W, N]`` outputs.
+    """
+    W, n = planes[0].shape
+    rows = tile_rows(W)
+    bc, nb, padded = _tile(n, min(block, tile_cols_cap(rows)))
+    xs = [_pad_blocks(x, n, nb, bc) for x in planes]
+    sc = _scalar_rows(W, *scalars)
+    spec = pl.BlockSpec((rows, bc), lambda i, j: (i, j))
+    outs = pl.pallas_call(
+        kernel,
+        grid=(W // rows, nb),
+        in_specs=[spec] * len(xs) + [pl.BlockSpec((rows, sc.shape[1]),
+                                                  lambda i, j: (i, 0))],
+        out_specs=[spec] * len(out_from),
+        out_shape=[jax.ShapeDtypeStruct(xs[i].shape, xs[i].dtype)
+                   for i in out_from],
+        input_output_aliases={} if padded else {i: o for o, i in enumerate(out_from)},
+        interpret=interpret,
+    )(*xs, sc)
+    return [o[:, :n] for o in outs]
+
+
+def _flat_kernel(theta_ref, peer_ref, v_ref, g_ref, sc_ref,
+                 theta_out_ref, v_out_ref):
+    t = theta_ref[...].astype(jnp.float32)
+    p = peer_ref[...].astype(jnp.float32)
+    v = v_ref[...].astype(jnp.float32)
+    g = g_ref[...].astype(jnp.float32)
+    sc = sc_ref[...]
+    coef, eta, mu = sc[:, 0:1], sc[:, 1:2], sc[:, 2:3]
+    v_new = mu * v - eta * g
+    t_new = t - coef * (t - p) - eta * g + mu * v_new
+    theta_out_ref[...] = t_new.astype(theta_out_ref.dtype)
+    v_out_ref[...] = v_new.astype(v_out_ref.dtype)
+
+
+def _flat_nag_kernel(theta_ref, v_ref, g_ref, sc_ref, theta_out_ref, v_out_ref):
+    t = theta_ref[...].astype(jnp.float32)
+    v = v_ref[...].astype(jnp.float32)
+    g = g_ref[...].astype(jnp.float32)
+    sc = sc_ref[...]
+    eta, mu = sc[:, 0:1], sc[:, 1:2]
+    v_new = mu * v - eta * g
+    theta_out_ref[...] = (t - eta * g + mu * v_new).astype(theta_out_ref.dtype)
+    v_out_ref[...] = v_new.astype(v_out_ref.dtype)
+
+
 @functools.partial(jax.jit, static_argnames=("block", "interpret"))
 def fused_flat_elastic_nag_update(theta, peer, v, g, coef, eta, mu, *,
                                   block: int = BLOCK, interpret: bool = False):
@@ -157,31 +154,16 @@ def fused_flat_elastic_nag_update(theta, peer, v, g, coef, eta, mu, *,
 
     theta/peer/v/g: [W, N] flat replica buffers (repro.common.flat layout);
     coef: scalar or [W] per-replica moving rate * participation gate;
-    eta/mu: scalars (traced values OK — they ride in a VMEM scalar row, so lr
-    schedules don't retrigger compilation). Returns (theta', v') [W, N] —
-    when the tiling covers N exactly (any N <= block, or block | N) the theta
-    and v inputs are ALIASED to the outputs, so donated resident buffers
-    update truly in place (no double HBM residency).
+    eta/mu: scalars (traced values OK — they ride in the scalar operand, so
+    lr schedules don't retrigger compilation). Returns (theta', v') [W, N] —
+    when the tiling covers N exactly the theta and v inputs are ALIASED to
+    the outputs, so donated resident buffers update truly in place (no
+    double HBM residency).
     """
-    W, n = theta.shape
-    block, nblocks, padded = _tile(n, block)
-    tf, pf = _pad_blocks(theta, n, nblocks, block), _pad_blocks(peer, n, nblocks, block)
-    vf, gf = _pad_blocks(v, n, nblocks, block), _pad_blocks(g, n, nblocks, block)
-    sc = _scalar_rows(W, coef, eta, mu)
-
-    spec = pl.BlockSpec((1, block), lambda i, j: (i, j))
-    sc_spec = pl.BlockSpec((1, 3), lambda i, j: (i, 0))
-    t_new, v_new = pl.pallas_call(
-        _flat_kernel,
-        grid=(W, nblocks),
-        in_specs=[spec, spec, spec, spec, sc_spec],
-        out_specs=[spec, spec],
-        out_shape=[jax.ShapeDtypeStruct((W, nblocks * block), theta.dtype),
-                   jax.ShapeDtypeStruct((W, nblocks * block), v.dtype)],
-        input_output_aliases={} if padded else {0: 0, 2: 1},
-        interpret=interpret,
-    )(tf, pf, vf, gf, sc)
-    return t_new[:, :n], v_new[:, :n]
+    t_new, v_new = plane_call(_flat_kernel, [theta, peer, v, g],
+                              [coef, eta, mu], (0, 2), block=block,
+                              interpret=interpret)
+    return t_new, v_new
 
 
 @functools.partial(jax.jit, static_argnames=("block", "interpret"))
@@ -192,22 +174,19 @@ def fused_flat_nag_update(theta, v, g, eta, mu, *,
     Returns (theta', v'), with theta/v aliased into the outputs (in-place)
     whenever the tiling covers N exactly — see
     :func:`fused_flat_elastic_nag_update`."""
-    W, n = theta.shape
-    block, nblocks, padded = _tile(n, block)
-    tf, vf = _pad_blocks(theta, n, nblocks, block), _pad_blocks(v, n, nblocks, block)
-    gf = _pad_blocks(g, n, nblocks, block)
-    sc = _scalar_rows(W, eta, mu)
+    t_new, v_new = plane_call(_flat_nag_kernel, [theta, v, g], [eta, mu],
+                              (0, 1), block=block, interpret=interpret)
+    return t_new, v_new
 
-    spec = pl.BlockSpec((1, block), lambda i, j: (i, j))
-    sc_spec = pl.BlockSpec((1, 2), lambda i, j: (i, 0))
-    t_new, v_new = pl.pallas_call(
-        _flat_nag_kernel,
-        grid=(W, nblocks),
-        in_specs=[spec, spec, spec, sc_spec],
-        out_specs=[spec, spec],
-        out_shape=[jax.ShapeDtypeStruct((W, nblocks * block), theta.dtype),
-                   jax.ShapeDtypeStruct((W, nblocks * block), v.dtype)],
-        input_output_aliases={} if padded else {0: 0, 1: 1},
-        interpret=interpret,
-    )(tf, vf, gf, sc)
-    return t_new[:, :n], v_new[:, :n]
+
+@functools.partial(jax.jit, static_argnames=("eta", "mu", "block", "interpret"))
+def fused_elastic_nag_update(theta, peer, v, g, coef_gate, *, eta: float, mu: float,
+                             block: int = BLOCK, interpret: bool = False):
+    """theta/peer/v/g: same-shape arrays (any rank); coef_gate: scalar f32
+    (= alpha * participation gate). Returns (theta', v') — the flat kernel on
+    the arrays seen as one-row planes."""
+    row = lambda x: x.reshape(1, -1)
+    t_new, v_new = fused_flat_elastic_nag_update(
+        row(theta), row(peer), row(v), row(g), coef_gate, eta, mu,
+        block=block, interpret=interpret)
+    return t_new.reshape(theta.shape), v_new.reshape(v.shape)

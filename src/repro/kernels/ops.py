@@ -1,10 +1,11 @@
 """Jit'd dispatch wrappers for the Pallas kernels.
 
-On TPU the kernels run compiled; everywhere else (this CPU container, unit
-tests) they execute in interpret mode, which runs the kernel body in Python
-per grid step — bit-faithful to the TPU schedule, slow, so callers that just
-need the math (training loops on CPU) should use the ref path via
-``use_kernel=False``.
+On the TPU every entry point runs its compiled Pallas kernel. Off the TPU
+the default (``use_kernel=None``) runs the jnp oracle from
+:mod:`repro.kernels.ref`: that path is for tests and CPU rehearsals, never a
+stand-in for the chip. ``use_kernel=True`` off the TPU runs the kernel body
+in interpret mode — bit-faithful to the kernel, slow — which is how the tests
+check kernel-vs-oracle parity.
 """
 from __future__ import annotations
 

@@ -75,8 +75,10 @@ def stochastic_uniform(idx, seed):
     x = (x ^ (x >> 16)) * jnp.uint32(0x7FEB352D)
     x = (x ^ (x >> 15)) * jnp.uint32(0x846CA68B)
     x = x ^ (x >> 16)
-    # top 24 bits -> [0, 1) with full float32 resolution
-    return (x >> 8).astype(jnp.float32) * jnp.float32(1.0 / (1 << 24))
+    # top 24 bits -> [0, 1) with full float32 resolution (through int32:
+    # exact for 24-bit values, and Mosaic has no uint32 -> f32 cast)
+    return ((x >> 8).astype(jnp.int32).astype(jnp.float32)
+            * jnp.float32(1.0 / (1 << 24)))
 
 
 def _pad_to_blocks(x, block: int):

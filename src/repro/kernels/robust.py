@@ -22,15 +22,15 @@ import functools
 
 import jax
 import jax.numpy as jnp
-from jax.experimental import pallas as pl
 
-from repro.kernels.fused_update import BLOCK, _pad_blocks, _scalar_rows, _tile
+from repro.kernels.fused_update import BLOCK, plane_call
 
 
 def _robust_kernel(theta_ref, delta_ref, sc_ref, out_ref):
     t = theta_ref[...].astype(jnp.float32)
     d = delta_ref[...].astype(jnp.float32)
-    scale, thr = sc_ref[0, 0], sc_ref[0, 1]
+    sc = sc_ref[...]
+    scale, thr = sc[:, 0:1], sc[:, 1:2]
     keep = (jnp.abs(d) <= thr).astype(jnp.float32)
     out_ref[...] = (t + scale * (d * keep)).astype(out_ref.dtype)
 
@@ -39,24 +39,9 @@ def _robust_kernel(theta_ref, delta_ref, sc_ref, out_ref):
 def robust_flat_apply(theta, delta, scale, thr, *, block: int = BLOCK,
                       interpret: bool = False):
     """theta/delta: [W, N] flat buffers; scale/thr: scalar or [W] per-replica
-    (traced OK — they ride in a VMEM scalar row). Returns theta' [W, N], with
+    (traced OK — they ride in the scalar operand). Returns theta' [W, N], with
     theta aliased into the output (in-place) when the tiling covers N exactly.
     """
-    W, n = theta.shape
-    block, nblocks, padded = _tile(n, block)
-    tf = _pad_blocks(theta, n, nblocks, block)
-    df = _pad_blocks(delta, n, nblocks, block)
-    sc = _scalar_rows(W, scale, thr)
-
-    spec = pl.BlockSpec((1, block), lambda i, j: (i, j))
-    sc_spec = pl.BlockSpec((1, 2), lambda i, j: (i, 0))
-    out = pl.pallas_call(
-        _robust_kernel,
-        grid=(W, nblocks),
-        in_specs=[spec, spec, sc_spec],
-        out_specs=spec,
-        out_shape=jax.ShapeDtypeStruct((W, nblocks * block), theta.dtype),
-        input_output_aliases={} if padded else {0: 0},
-        interpret=interpret,
-    )(tf, df, sc)
-    return out[:, :n]
+    (out,) = plane_call(_robust_kernel, [theta, delta], [scale, thr], (0,),
+                        block=block, interpret=interpret)
+    return out

@@ -1,11 +1,14 @@
 import os
+os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
 
 """Multi-pod dry-run: lower + compile every (architecture x input shape) on
 the production meshes, record memory/cost/roofline artifacts.
 
-MUST be run as its own process (the XLA_FLAGS line above precedes every other
-import — jax locks the device count on first init). Results accumulate under
+MUST be run as its own process (the JAX_PLATFORMS/XLA_FLAGS lines above
+precede every other import — jax locks the platform and device count on first
+init; the 512 placeholder devices are CPU devices, never an attached chip).
+Results accumulate under
 ``experiments/dryrun/<mesh>/<arch>__<shape>__<program>.json`` so interrupted
 sweeps resume where they left off.
 
@@ -22,7 +25,6 @@ import traceback    # noqa: E402
 import jax          # noqa: E402
 
 from repro.analysis import roofline as rf                       # noqa: E402
-from repro.common import compat                                 # noqa: E402
 from repro.common.config import INPUT_SHAPES                    # noqa: E402
 from repro.configs import ARCH_IDS, get_config                  # noqa: E402
 from repro.launch import plans as plans_mod                     # noqa: E402
@@ -51,13 +53,13 @@ def run_cell(arch: str, shape_name: str, *, multi_pod: bool, force: bool = False
         t0 = time.time()
         try:
             if prog.mesh is not None:
-                with compat.set_mesh(prog.mesh):
+                with jax.set_mesh(prog.mesh):
                     lowered = prog.jitted.lower(*prog.args)
             else:
                 lowered = prog.jitted.lower(*prog.args)
             compiled = lowered.compile()
             mem = compiled.memory_analysis()
-            cost = compat.cost_analysis(compiled)
+            cost = compiled.cost_analysis() or {}
             hlo_text = compiled.as_text()
             roof = rf.analyze_program(arch, plan.shape, prog.name, hlo_text, cfg, chips,
                                       peak_memory=getattr(mem, "temp_size_in_bytes", None))

@@ -11,16 +11,13 @@ Two views of the same chips:
   replica group along ``fsdp``, tensor-parallel along ``model``.
 
 Both are FUNCTIONS so importing this module never touches jax device state.
-
-Mesh construction goes through :mod:`repro.common.compat` so the same code
-runs on the pinned container JAX (no ``AxisType``, tuple-style
-``AbstractMesh``) and on current JAX.
 """
 from __future__ import annotations
 
 import jax
 
-from repro.common.compat import AxisType, abstract_mesh, make_mesh
+from jax.sharding import AbstractMesh, AxisType
+
 from repro.common.config import MeshConfig
 
 # gossip/"worker" axes of the worker mesh, outermost first
@@ -31,21 +28,21 @@ REPLICA_AXES = ("fsdp", "model")   # axes *within* one gossip replica group
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_worker_mesh(cfg: MeshConfig):
     """Production mesh with the data axis factored into (worker, fsdp)."""
     shape = (cfg.pods, cfg.workers_per_pod, cfg.fsdp, cfg.model)
     axes = ("pod", "worker", "fsdp", "model")
-    return make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_abstract_worker_mesh(cfg: MeshConfig):
     """Device-free stand-in with the worker-mesh axes: shape math (specs,
     input_specs) without owning 256 placeholder devices."""
-    return abstract_mesh((cfg.pods, cfg.workers_per_pod, cfg.fsdp, cfg.model),
-                         ("pod", "worker", "fsdp", "model"))
+    return AbstractMesh((cfg.pods, cfg.workers_per_pod, cfg.fsdp, cfg.model),
+                        ("pod", "worker", "fsdp", "model"))
 
 
 def make_host_mesh(num_workers: int = 1):
@@ -54,5 +51,5 @@ def make_host_mesh(num_workers: int = 1):
     n = len(jax.devices())
     assert n % num_workers == 0, (n, num_workers)
     shape = (1, num_workers, n // num_workers, 1)
-    return make_mesh(shape, ("pod", "worker", "fsdp", "model"),
-                     axis_types=(AxisType.Auto,) * 4)
+    return jax.make_mesh(shape, ("pod", "worker", "fsdp", "model"),
+                         axis_types=(AxisType.Auto,) * 4)

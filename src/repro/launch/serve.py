@@ -19,6 +19,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.api import GossipTrainer, available_engines, make_serve_program
+from repro.common.cache import enable_compile_cache
 from repro.common.config import MeshConfig, OptimizerConfig, ProtocolConfig
 from repro.configs import ARCH_IDS, get_config, get_reduced
 from repro.launch.mesh import make_host_mesh
@@ -106,6 +107,7 @@ def main() -> None:
                     choices=["poisson", "staggered"])
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    enable_compile_cache()
     out = run(args.arch, reduced=args.reduced, engine=args.engine,
               workers=args.workers, method=args.method, p=args.p,
               alpha=args.alpha, lr=args.lr, slots=args.slots,

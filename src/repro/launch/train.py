@@ -43,6 +43,7 @@ import numpy as np
 
 from repro.api import GossipTrainer, available_engines, available_protocols
 from repro.comm import available_codecs
+from repro.common.cache import enable_compile_cache
 from repro.common.config import (FaultConfig, FleetConfig, HeteroConfig,
                                  MeshConfig, ObsConfig, OptimizerConfig,
                                  ProtocolConfig, ShardConfig)
@@ -195,13 +196,18 @@ def run(arch: str, *, reduced: bool, steps: int, method: str, p: float, tau: int
     history = []
     t0 = time.time()
     for i in range(steps):
-        state, m = trainer.step(state, as_batch(next(batches)))
+        batch = as_batch(next(batches))
+        t_step = time.perf_counter()
+        state, m = trainer.step(state, batch)
         if i % log_every == 0 or i == steps - 1:
+            # a logged step is timed to the end of its device work
+            jax.block_until_ready(state.theta)
+            step_s = time.perf_counter() - t_step
             # diagnostics read the resident flat plane directly (identical
             # numbers to the per-leaf tree: padding is zeros on both sides of
             # the consensus difference) — no pytree views on the log path
             div = divergence_metrics(state.theta)
-            rec = {"step": i, "loss": float(m["loss"]),
+            rec = {"step": i, "step_s": step_s, "loss": float(m["loss"]),
                    "consensus_rel": float(div["consensus_rel"]),
                    "fired": bool(m["fired"]),
                    "comm_mb": round(float(m["comm_bytes"]) / 1e6, 3)}
@@ -225,7 +231,7 @@ def run(arch: str, *, reduced: bool, steps: int, method: str, p: float, tau: int
         print(f"summarize: {hint}")
     elif "trace" in exported:
         print(f"view: load {exported['trace']} at https://ui.perfetto.dev")
-    return state, history
+    return trainer, state, history
 
 
 def main() -> None:
@@ -311,6 +317,7 @@ def main() -> None:
     ap.add_argument("--production-mesh", action="store_true")
     ap.add_argument("--multi-pod", action="store_true")
     a = ap.parse_args()
+    enable_compile_cache()
     run(a.arch, reduced=a.reduced, steps=a.steps, method=a.method, p=a.p, tau=a.tau,
         alpha=a.alpha, workers=a.workers, global_batch=a.global_batch, seq=a.seq,
         lr=a.lr, checkpoint_dir=a.checkpoint_dir,

@@ -109,11 +109,7 @@ def maybe_shard(x, *spec):
     current mesh (or no mesh at all — unit tests on CPU) degrade to
     unconstrained. Each entry may be a name or tuple of names.
     """
-    try:
-        mesh = jax.sharding.get_abstract_mesh()   # ambient mesh (jax.set_mesh)
-        axes = set(mesh.axis_names) if mesh is not None else set()
-    except Exception:
-        axes = set()
+    axes = set(jax.sharding.get_abstract_mesh().axis_names)   # ambient mesh
     if not axes:
         return x
 

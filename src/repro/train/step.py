@@ -148,26 +148,37 @@ class DistTrainer:
         return jax.lax.with_sharding_constraint(
             bufs, {k: NamedSharding(self.mesh, specs[k]) for k in bufs})
 
-    def init_state(self, key) -> FlatState:
-        """Flatten ONCE into the resident plane; pytrees do not survive init."""
-        single = self.init_fn(key)
+    def _stack_plane(self, single):
+        """One replica's params -> the sharded ``[W, total]`` plane buffers."""
         stacked = jax.tree.map(lambda x: jnp.broadcast_to(x[None], (self.W,) + x.shape), single)
         theta = self.flat_spec.flatten(stacked)
         if self.shard_layout is not None:
             from repro import shard as shard_plane
             theta = shard_plane.pad_bufs(theta, self.shard_layout)
-        theta = self._constrain_bufs(theta)
+        return self._constrain_bufs(theta)
+
+    def init_state(self, key) -> FlatState:
+        """Flatten ONCE into the resident plane; pytrees do not survive init."""
+        single = self.init_fn(key)
+        # stacked inside one program: each device builds only its own rows
+        # (built eagerly, the whole W-replica stack and its flattened copy
+        # would land on the first device before the resharding)
+        theta = jax.jit(self._stack_plane)(single)
         vel = jax.tree.map(jnp.zeros_like, theta)
-        center = (self.flat_spec.with_lead(()).flatten(single)
+        # replicated leaves carry the step programs' own output sharding, so
+        # the first step compiles the program every later step reuses
+        rep = NamedSharding(self.mesh, P())
+        center = (jax.device_put(self.flat_spec.with_lead(()).flatten(single), rep)
                   if self._impl.uses_center else None)
         comm_state = comm.CommState(None)
         if self._codec_stateful:
-            res = {k: jnp.zeros(b.shape, jnp.float32) for k, b in theta.items()}
+            res = {k: jnp.zeros_like(b, jnp.float32) for k, b in theta.items()}
             comm_state = comm.CommState(self._constrain_bufs(res))
+        # two counters, two buffers: the step programs donate both
+        counter = lambda: jax.device_put(jnp.zeros((), jnp.int32), rep)
         return FlatState(spec=self.flat_spec, theta=theta,
-                         opt=OptState(jnp.zeros((), jnp.int32), vel, {}),
-                         center=center, comm=comm_state,
-                         step=jnp.zeros((), jnp.int32))
+                         opt=OptState(counter(), vel, {}),
+                         center=center, comm=comm_state, step=counter())
 
     def state_shapes(self) -> FlatState:
         """ShapeDtypeStructs for the dry-run (no allocation)."""
@@ -354,14 +365,13 @@ class DistTrainer:
         only see local shards) — fused_nag(theta_bufs, v_bufs, g_bufs, eta,
         mu) -> (theta'_bufs, v'_bufs)."""
         if self._fused_nag is None:
-            from repro.common import compat
             bspecs = self.buf_specs
-            self._fused_nag = compat.shard_map(
+            self._fused_nag = jax.shard_map(
                 lambda p, v, g, eta, mu: ops.fused_bufs_nag(p, v, g, eta, mu),
-                self.mesh,
+                mesh=self.mesh,
                 in_specs=(bspecs, bspecs, bspecs, P(), P()),
                 out_specs=(bspecs, bspecs),
-                manual_axes=set(self.mesh.axis_names))
+                axis_names=frozenset(self.mesh.axis_names), check_vma=False)
         return self._fused_nag
 
     # jit entry points ------------------------------------------------------

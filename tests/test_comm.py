@@ -76,7 +76,8 @@ def _buf(W=3, N=1000, seed=0):
     return jax.random.normal(jax.random.PRNGKey(seed), (W, N))
 
 
-@pytest.mark.parametrize("N,block", [(1000, 256), (128, 128), (700, 512)])
+@pytest.mark.parametrize("N,block", [(1000, 256), (128, 128), (700, 512),
+                                     (70 * 128, 128)])
 def test_q8_kernel_matches_oracle(N, block):
     buf = _buf(N=N)
     seeds = codec_seeds(3, jnp.arange(buf.shape[0]))
@@ -103,7 +104,8 @@ def test_q8_rounding_is_seed_deterministic_and_varies_with_seed():
     assert np.any(np.asarray(a0) != np.asarray(a1))
 
 
-@pytest.mark.parametrize("N,k,block", [(1000, 13, 256), (512, 1, 512), (300, 8, 128)])
+@pytest.mark.parametrize("N,k,block", [(1000, 13, 256), (512, 1, 512), (300, 8, 128),
+                                       (70 * 128, 3, 128)])
 def test_topk_kernel_matches_oracle(N, k, block):
     buf = _buf(N=N, seed=4)
     res = 0.1 * _buf(N=N, seed=5)

@@ -13,10 +13,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:  # container without hypothesis: fixed-seed sweep
-    from _hypothesis_stub import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 
 import repro
 from repro.api import GossipTrainer, get_protocol, register_protocol, \

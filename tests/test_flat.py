@@ -217,6 +217,53 @@ def test_flat_nag_kernel_matches_ref():
     np.testing.assert_allclose(np.asarray(vk), np.asarray(vr_), rtol=1e-6, atol=1e-6)
 
 
+@pytest.mark.parametrize("W,N,block", [
+    (1, 3 * 8192, 8192),      # one replica: (1, cols) tiles of the [1, N] row
+    (16, 2048, 512),          # W a multiple of 8: 8-row tiles, 2 row groups
+    (3, 925 * 128, fu.BLOCK),  # divisor tiling (185-lane tiles), all rows
+])
+def test_flat_kernels_every_tile_layout(W, N, block):
+    """Each tile layout of kernels.fused_update.plane_call matches the
+    oracles (to the fused tests' f32 tolerance) and keeps the in-place
+    aliasing."""
+    from repro.kernels import robust
+    t, p, v, g = flat_inputs(W=W, N=N, seed=W)
+    close = lambda a, b: np.testing.assert_allclose(
+        np.asarray(a), np.asarray(b), rtol=1e-6, atol=1e-6)
+    coef = jnp.linspace(0.0, 1.0, W)
+    tk, vk = fu.fused_flat_elastic_nag_update(t, p, v, g, coef, 0.01, 0.9,
+                                              block=block, interpret=True)
+    tr_, vr_ = ref.fused_flat_elastic_nag_update(t, p, v, g, coef, 0.01, 0.9)
+    close(tk, tr_)
+    close(vk, vr_)
+    tk, vk = fu.fused_flat_nag_update(t, v, g, 0.05, 0.99, block=block,
+                                      interpret=True)
+    tr_, vr_ = ref.fused_flat_nag_update(t, v, g, 0.05, 0.99)
+    close(tk, tr_)
+    close(vk, vr_)
+    thr = jnp.full((W,), 1.5)
+    ok = robust.robust_flat_apply(t, p, coef, thr, block=block, interpret=True)
+    close(ok, ref.robust_flat_apply(t, p, coef, thr))
+    jx = jax.make_jaxpr(lambda a, b, c: fu.fused_flat_nag_update(
+        a, b, c, 0.01, 0.9, block=block, interpret=True))(t, v, g)
+    (eq,) = _pallas_eqns(jx.jaxpr)
+    assert dict(eq.params["input_output_aliases"]) == {0: 0, 1: 1}
+
+
+def _pallas_eqns(jaxpr):
+    out = []
+    for e in jaxpr.eqns:
+        if e.primitive.name == "pallas_call":
+            out.append(e)
+        for v in e.params.values():
+            for sub in (v if isinstance(v, (list, tuple)) else [v]):
+                if hasattr(sub, "jaxpr"):
+                    out += _pallas_eqns(sub.jaxpr)
+                elif hasattr(sub, "eqns"):
+                    out += _pallas_eqns(sub)
+    return out
+
+
 def test_flat_kernel_traced_eta_single_compile():
     """eta/mu ride in the scalar operand: a traced learning rate must work
     (lr schedules don't retrigger compilation)."""

@@ -11,19 +11,11 @@ Contract anchors:
 - flow-control balances persist through checkpoints; restoring under a
   different fleet config is refused.
 """
-import sys
-from pathlib import Path
-
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-
-sys.path.insert(0, str(Path(__file__).parent))
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:  # container without hypothesis: fixed-seed sweep
-    from _hypothesis_stub import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 
 from repro.api import GossipTrainer
 from repro.common.config import (FleetConfig, HeteroConfig, OptimizerConfig,

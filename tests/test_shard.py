@@ -22,10 +22,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:  # container without hypothesis: fixed-seed sweep
-    from _hypothesis_stub import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 
 from repro import shard as shard_plane
 from repro.api import GossipTrainer

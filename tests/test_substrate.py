@@ -7,10 +7,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:  # container without hypothesis: fixed-seed sweep
-    from _hypothesis_stub import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 
 from repro.common.config import OptimizerConfig, ProtocolConfig
 from repro.optim import make_optimizer, param_update, velocity_update
@@ -151,10 +148,10 @@ def test_latest_step_path(tmp_path):
 
 def test_spec_for_divisibility_and_axis_reuse():
     from jax.sharding import PartitionSpec as P
-    from repro.common.compat import AxisType, make_mesh
+    from jax.sharding import AxisType
     from repro.launch.sharding import spec_for
-    mesh = make_mesh((1, 1), ("fsdp", "model"),
-                     axis_types=(AxisType.Auto,) * 2)
+    mesh = jax.make_mesh((1, 1), ("fsdp", "model"),
+                         axis_types=(AxisType.Auto,) * 2)
     # single-device mesh: everything divisible, axis sizes 1
     s = spec_for((8, 16), ("embed", "ffn"), mesh)
     assert s == P("fsdp", "model")
@@ -165,11 +162,11 @@ def test_spec_for_divisibility_and_axis_reuse():
 
 def test_spec_for_indivisible_falls_back_to_none():
     from jax.sharding import PartitionSpec as P
-    from repro.common.compat import abstract_mesh
+    from jax.sharding import AbstractMesh
     from repro.launch.sharding import spec_for
-    # need >1-sized axis; skip if the runtime only has 1 device — construct
-    # an abstract mesh instead
-    mesh = abstract_mesh((4, 2), ("fsdp", "model"))
+    # need >1-sized axis; the runtime may only have 1 device — construct an
+    # abstract mesh instead
+    mesh = AbstractMesh((4, 2), ("fsdp", "model"))
     s = spec_for((6, 16), ("embed", "ffn"), mesh)   # 6 % 4 != 0
     assert s == P(None, "model")
 
