@@ -263,7 +263,15 @@ class GossipTrainer:
 
         Metrics are normalized to the unified cross-engine schema
         (:data:`repro.obs.schema.CORE_STEP_KEYS`) — additive only, engines'
-        own keys are never removed."""
+        own keys are never removed.
+
+        The whole call is the host span ``train_step`` of a ``jax.profiler``
+        trace: it lands on the host plane beside the device's ops, on the
+        same clock (the span costs one enter and exit when no trace runs)."""
+        with jax.profiler.TraceAnnotation("train_step"):
+            return self._step(state, batch)
+
+    def _step(self, state, batch):
         from repro.obs import schema as obs_schema
         step_idx = self._host_steps
         state, metrics = self._backend.step(state, batch)

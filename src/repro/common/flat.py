@@ -148,8 +148,12 @@ class FlatSpec:
         lands every leaf's cotangent in ONE zeros buffer per dtype bucket via
         in-place ``dynamic_update_slice`` (slots are disjoint), so gradients
         arrive already flat at plane-sized memory, with no concatenate and no
-        per-leaf pads — step memory stays independent of tree depth."""
-        return _views(self, bufs)
+        per-leaf pads — step memory stays independent of tree depth.
+
+        The slices and, in the backward pass, the scatter run under the
+        ``flat_views`` scope, so a device trace names their time."""
+        with jax.named_scope("flat_views"):
+            return _views(self, bufs)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(0,))

@@ -191,7 +191,8 @@ def make_gossip_step(mesh: Mesh, mesh_cfg: MeshConfig, cfg: ProtocolConfig,
             return fn
 
         branches = [branch(ax, pairs) for ax, pairs in schedule]
-        return jax.lax.switch(round_idx % n_rounds, branches, bufs)
+        with jax.named_scope("exchange"):
+            return jax.lax.switch(round_idx % n_rounds, branches, bufs)
 
     def exchange_flat(spec, bufs, residual, act, round_idx):
         """One gossip round over the local flat plane. Returns
@@ -207,17 +208,19 @@ def make_gossip_step(mesh: Mesh, mesh_cfg: MeshConfig, cfg: ProtocolConfig,
         if codec is None:
             peer, peer_act = switch_exchange(bufs, act, round_idx)
             return peer, peer_act, None
-        seeds = jnp.reshape(comm.codec_seeds(round_idx, _seed_index()), (1,))
-        res_bufs = spec.flatten(residual) if stateful else {}
-        wires, new_res = {}, {}
-        for k, b in bufs.items():
-            wire, r2 = codec.encode(b, seeds, residual=res_bufs.get(k))
-            wires[k] = codec.pack(wire)
-            if stateful:
-                new_res[k] = jnp.where(act > 0, r2, res_bufs[k])
+        with jax.named_scope("codec"):
+            seeds = jnp.reshape(comm.codec_seeds(round_idx, _seed_index()), (1,))
+            res_bufs = spec.flatten(residual) if stateful else {}
+            wires, new_res = {}, {}
+            for k, b in bufs.items():
+                wire, r2 = codec.encode(b, seeds, residual=res_bufs.get(k))
+                wires[k] = codec.pack(wire)
+                if stateful:
+                    new_res[k] = jnp.where(act > 0, r2, res_bufs[k])
         peer_wires, peer_act = switch_exchange(wires, act, round_idx)
-        peer = {k: codec.decode_wire(peer_wires[k], spec.totals[k]).astype(b.dtype)
-                for k, b in bufs.items()}
+        with jax.named_scope("codec"):
+            peer = {k: codec.decode_wire(peer_wires[k], spec.totals[k]).astype(b.dtype)
+                    for k, b in bufs.items()}
         return peer, peer_act, (new_res if stateful else None)
 
     def local_update(params, residual, active_scalar, round_idx):
@@ -234,7 +237,9 @@ def make_gossip_step(mesh: Mesh, mesh_cfg: MeshConfig, cfg: ProtocolConfig,
             # compute in the storage dtype: f32 upcasts would materialize two
             # full f32 copies of the replica shard (grok: +12 GB/chip). On TPU
             # the fused mode does the f32 math per-tile in VMEM instead.
-            new = {k: b - gc.astype(b.dtype) * (b - peer[k]) for k, b in bufs.items()}
+            with jax.named_scope("mix"):
+                new = {k: b - gc.astype(b.dtype) * (b - peer[k])
+                       for k, b in bufs.items()}
             out = (spec.unflatten(new),)
         if stateful:
             out = out + (spec.unflatten(new_res, like=residual),)
@@ -253,8 +258,9 @@ def make_gossip_step(mesh: Mesh, mesh_cfg: MeshConfig, cfg: ProtocolConfig,
                                                 active_scalar, round_idx)
         gate, coef = impl.pair_gate_coef(active_scalar, peer_act)
         gc = (gate * coef).astype(jnp.float32)
-        out_t, out_v = kernel_ops.fused_bufs_elastic_nag(bufs, peer, vb, gb,
-                                                         gc, eta, mu)
+        with jax.named_scope("update"):
+            out_t, out_v = kernel_ops.fused_bufs_elastic_nag(bufs, peer, vb, gb,
+                                                             gc, eta, mu)
         outs = (spec.unflatten(out_t), spec.unflatten(out_v, like=velocity))
         if stateful:
             outs = outs + (spec.unflatten(new_res, like=residual),)

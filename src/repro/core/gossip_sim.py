@@ -231,7 +231,7 @@ class SimTrainer:
             proto = proto._replace(wire_dropped=jnp.zeros((), jnp.int32),
                                    wire_corrupt=jnp.zeros((), jnp.int32))
         proto = self._fleet_proto_seed(proto)
-        return FlatState(
+        state = FlatState(
             spec=spec,
             theta=theta,
             opt=self.optimizer.init(theta),
@@ -239,6 +239,10 @@ class SimTrainer:
             comm=comm.init_comm_state(self.codec, theta),
             key=jax.random.PRNGKey(seed),
             step=jnp.zeros((), jnp.int32))
+        # commit the state to the device it is on: the step returns committed
+        # arrays, so an uncommitted first state would compile the whole step a
+        # second time, for its first call alone
+        return jax.tree.map(lambda x: jax.device_put(x, x.sharding), state)
 
     def _codec_transmit(self, state: FlatState, active, publish=None,
                         col_gate=None):
@@ -379,7 +383,8 @@ class SimTrainer:
             return self.loss_fn(row_spec.views(bufs), xi, yi)
 
         losses, grads = jax.vmap(jax.value_and_grad(one_loss))(state.theta, x, y)
-        grads = protocols.gradient_transform(cfg, grads)
+        with jax.named_scope("grad_mean"):
+            grads = protocols.gradient_transform(cfg, grads)
 
         # communication-related component (lines 4-8), simultaneous, directly
         # on the resident buffers (one mixing einsum per dtype bucket)
@@ -445,13 +450,14 @@ class SimTrainer:
                 dropped = fm.drop_mask_jnp(state.step, self.num_workers)
 
         if self.codec is not None:
-            if corrupt_mask is not None:
-                transmit, comm_new, ok = self._codec_transmit_checked(
-                    state, active, publish, corrupt_mask, col_gate)
-                detected = ~ok
-            else:
-                transmit, comm_new = self._codec_transmit(state, active,
-                                                          publish, col_gate)
+            with jax.named_scope("codec"):
+                if corrupt_mask is not None:
+                    transmit, comm_new, ok = self._codec_transmit_checked(
+                        state, active, publish, corrupt_mask, col_gate)
+                    detected = ~ok
+                else:
+                    transmit, comm_new = self._codec_transmit(
+                        state, active, publish, col_gate)
         elif corrupt_mask is not None:
             # uncompressed wire: bitcast -> checksum -> corrupt -> verify
             from repro.faults import wire as fwire
@@ -471,18 +477,19 @@ class SimTrainer:
             from repro.api.protocols import WireFaults
             wire_faults = WireFaults(dropped=dropped, corrupt=detected)
 
-        if part_ids is not None:
-            from repro.fleet.partition import partitioned_comm_update
-            theta_comm, proto_new = partitioned_comm_update(
-                self._impl, sel_key, active, state.theta, proto0,
-                step=state.step, transmit=transmit, wire_faults=wire_faults,
-                part_ids=part_ids, plan=self._fleet_plan(spec))
-        else:
-            kw = ({"wire_bytes": self._wire_bytes(spec)}
-                  if self._pass_wire_bytes else {})
-            theta_comm, proto_new = protocols.comm_update(
-                cfg, sel_key, active, state.theta, proto0, step=state.step,
-                transmit=transmit, wire_faults=wire_faults, **kw)
+        with jax.named_scope("mix"):
+            if part_ids is not None:
+                from repro.fleet.partition import partitioned_comm_update
+                theta_comm, proto_new = partitioned_comm_update(
+                    self._impl, sel_key, active, state.theta, proto0,
+                    step=state.step, transmit=transmit, wire_faults=wire_faults,
+                    part_ids=part_ids, plan=self._fleet_plan(spec))
+            else:
+                kw = ({"wire_bytes": self._wire_bytes(spec)}
+                      if self._pass_wire_bytes else {})
+                theta_comm, proto_new = protocols.comm_update(
+                    cfg, sel_key, active, state.theta, proto0, step=state.step,
+                    transmit=transmit, wire_faults=wire_faults, **kw)
         return self._step_epilogue(state, worker_mask, theta_comm, proto_new,
                                    comm_new, grads, losses, active, key)
 
@@ -490,39 +497,40 @@ class SimTrainer:
                        comm_new, grads, losses, active, key):
         """Optimizer update + metrics — the tail of :meth:`_step`, shared by
         the normal path and the async message-mode (``defer_comm``) path."""
-        if self.fused_update:
-            # fused flat-plane path: lines 3, 7 and 9 in ONE pass per dtype
-            # bucket, in place (donated buffers alias the kernel outputs).
-            # Setting peer := theta_comm and coef := 1 makes the kernel's
-            # elastic term exactly the comm displacement theta_comm - theta,
-            # for ANY pairwise mixing (incl. fan-in > 1).
-            ocfg = self.optimizer_cfg
-            grads_c = _clip(ocfg, grads)
-            eta = lr_at(ocfg, state.opt.step)
-            theta_new, v_new = ops.fused_bufs_elastic_nag(
-                state.theta, theta_comm, state.opt.mu, grads_c,
-                jnp.ones((self.num_workers,), jnp.float32),
-                eta, ocfg.momentum)
-            opt_new = OptState(state.opt.step + 1, v_new, {})
-        else:
-            # per-bucket reference path (the fused path's parity target)
-            # elastic/gossip displacement relative to theta_t:
-            comm_delta = jax.tree.map(lambda a, b: a - b, theta_comm, state.theta)
-
-            # optimizer update (lines 3 & 9)
-            if self.optimizer_cfg.name == "nag":
-                v_new, opt_new = velocity_update(self.optimizer_cfg, state.opt, grads)
-                # clip the -eta*g term too: velocity_update clips internally,
-                # and make_optimizer("nag") uses the clipped grads for BOTH
-                # terms — so must line 9 here (and the fused path does)
-                theta_grad = param_update(self.optimizer_cfg, state.opt.step,
-                                          state.theta,
-                                          _clip(self.optimizer_cfg, grads), v_new)
+        with jax.named_scope("update"):
+            if self.fused_update:
+                # fused flat-plane path: lines 3, 7 and 9 in ONE pass per dtype
+                # bucket, in place (donated buffers alias the kernel outputs).
+                # Setting peer := theta_comm and coef := 1 makes the kernel's
+                # elastic term exactly the comm displacement theta_comm - theta,
+                # for ANY pairwise mixing (incl. fan-in > 1).
+                ocfg = self.optimizer_cfg
+                grads_c = _clip(ocfg, grads)
+                eta = lr_at(ocfg, state.opt.step)
+                theta_new, v_new = ops.fused_bufs_elastic_nag(
+                    state.theta, theta_comm, state.opt.mu, grads_c,
+                    jnp.ones((self.num_workers,), jnp.float32),
+                    eta, ocfg.momentum)
+                opt_new = OptState(state.opt.step + 1, v_new, {})
             else:
-                theta_grad, opt_new = self.optimizer.update(grads, state.opt, state.theta)
+                # per-bucket reference path (the fused path's parity target)
+                # elastic/gossip displacement relative to theta_t:
+                comm_delta = jax.tree.map(lambda a, b: a - b, theta_comm, state.theta)
 
-            theta_new = jax.tree.map(lambda tg, d: tg + d.astype(tg.dtype),
-                                     theta_grad, comm_delta)
+                # optimizer update (lines 3 & 9)
+                if self.optimizer_cfg.name == "nag":
+                    v_new, opt_new = velocity_update(self.optimizer_cfg, state.opt, grads)
+                    # clip the -eta*g term too: velocity_update clips internally,
+                    # and make_optimizer("nag") uses the clipped grads for BOTH
+                    # terms — so must line 9 here (and the fused path does)
+                    theta_grad = param_update(self.optimizer_cfg, state.opt.step,
+                                              state.theta,
+                                              _clip(self.optimizer_cfg, grads), v_new)
+                else:
+                    theta_grad, opt_new = self.optimizer.update(grads, state.opt, state.theta)
+
+                theta_new = jax.tree.map(lambda tg, d: tg + d.astype(tg.dtype),
+                                         theta_grad, comm_delta)
 
         metrics = {
             "loss_mean": jnp.mean(losses),

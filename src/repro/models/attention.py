@@ -117,6 +117,7 @@ def gqa_qkv(p, x, positions, theta):
     return q, k, v
 
 
+@jax.named_scope("attention")
 def gqa_forward(p, x, cfg: ModelConfig, *, window=0, positions=None, chunk: int = 1024):
     B, S, _ = x.shape
     positions = jnp.arange(S) if positions is None else positions
@@ -158,6 +159,7 @@ def init_cross_attn(key, cfg: ModelConfig, kv_dim: int, dtype=jnp.float32):
     })
 
 
+@jax.named_scope("attention")
 def cross_attn_forward(p, x, cond, cfg: ModelConfig, chunk: int = 1024):
     """x: [B, S, d]; cond: [B, T, kv_dim] (stubbed modality embeddings)."""
     q = jnp.einsum("bsd,dhk->bshk", x, p["wq"].astype(x.dtype))
@@ -206,6 +208,7 @@ def _mla_qc(p, x, cfg: ModelConfig, positions):
     return q_nope, q_rope, c_kv, k_rope
 
 
+@jax.named_scope("attention")
 def mla_forward(p, x, cfg: ModelConfig, *, positions=None, chunk: int = 1024):
     """Training/prefill with the ABSORBED formulation: scores and values are
     computed against the compact latent c_kv, so no [B,S,H,hd] K/V are ever

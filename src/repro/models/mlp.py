@@ -22,6 +22,7 @@ def init_ffn(key, d_model: int, d_ff: int, activation: str, dtype=jnp.float32):
     })
 
 
+@jax.named_scope("ffn")
 def ffn_forward(p, x, activation: str):
     act = activation_fn(activation)
     if "w_gate" in p:

@@ -189,6 +189,7 @@ def abstract_cache(cfg: ModelConfig, batch: int, max_len: int, *, dtype=jnp.floa
 # embedding / head helpers
 # ---------------------------------------------------------------------------
 
+@jax.named_scope("embed")
 def embed_tokens(params, cfg: ModelConfig, tokens):
     """tokens: [B, S] (or [B, K, S] for audio codebooks) -> [B, S, d]."""
     emb = params["embed"]
@@ -230,7 +231,11 @@ def _scan_segment(seg: Segment, seg_params, x, cfg: ModelConfig, cond):
 
     if cfg.remat:
         body = jax.checkpoint(body)
-    (x, aux), _ = jax.lax.scan(body, (x, jnp.zeros((), jnp.float32)), (seg_params, windows))
+    # the scan's own work (per-layer weight slices, norms, residual adds) is
+    # named; attention and ffn inside carry their own, innermost, scopes
+    with jax.named_scope("layer_scan"):
+        (x, aux), _ = jax.lax.scan(body, (x, jnp.zeros((), jnp.float32)),
+                                   (seg_params, windows))
     return x, aux
 
 
@@ -267,6 +272,7 @@ def forward(params, cfg: ModelConfig, tokens, cond=None):
     return rmsnorm(params["final_norm"], x, cfg.norm_eps), aux_total
 
 
+@jax.named_scope("head_loss")
 def chunked_ce_loss(params, cfg: ModelConfig, hidden, labels, chunk: int = 256):
     """Cross-entropy without materializing [B, S, V]: scan over seq chunks.
 

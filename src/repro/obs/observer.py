@@ -18,10 +18,13 @@ engines already materialize —
   (one batched ``jax.device_get`` per sampled step) — sink totals equal the
   state's totals exactly, by construction.
 
-Timestamps: VIRTUAL seconds on the async engine's worker tracks, host wall
-seconds since recorder start everywhere else (the trainer track mixes in wall
-time under ``engine="async"`` — a documented, deliberate asymmetry: virtual
-time is the async engine's semantic clock).
+Timestamps: VIRTUAL seconds on the async engine's worker tracks, host seconds
+on the profiler's clock everywhere else (the trainer track mixes in host time
+under ``engine="async"`` — a documented, deliberate asymmetry: virtual time is
+the async engine's semantic clock). The profiler's host clock is the wall
+clock ``time.time_ns()`` reads, the clock ``jax.profiler`` stamps host spans
+with (``train_step``) and aligns the device's ops to, so an exported run
+overlays a device trace of the same run.
 
 The harvest is PIPELINED one step behind: each hook dispatches its device
 reads (the ``_draw_fn`` draws, a jitted donation-safe snapshot of the
@@ -67,7 +70,6 @@ class Observer:
         self.sink: Optional[MetricsSink] = (
             MetricsSink(cfg.metrics_path or None)
             if cfg.metrics_enabled() else None)
-        self._t0 = time.perf_counter()
         self._prev: Dict[str, float] = {}
         self._exported = False
         # one-step-deferred harvest state (see module docstring)
@@ -76,16 +78,13 @@ class Observer:
         self._snap_fn = None
 
     # ------------------------------------------------------------ utilities
-    def now(self) -> float:
-        """Host wall seconds since recorder start."""
-        return time.perf_counter() - self._t0
+    @staticmethod
+    def now() -> float:
+        """Host seconds on the profiler's clock (see module docstring)."""
+        return time.time_ns() * 1e-9
 
     def want(self, step: int) -> bool:
         return step % max(1, self.cfg.sample_every) == 0
-
-    @property
-    def tracing(self) -> bool:
-        return self.trace is not None
 
     def event(self, ev: str, t: float, step: int, worker: int = -1,
               **fields) -> None:

@@ -39,9 +39,6 @@ class TraceRecorder:
         e.update(fields)
         self.events.append(e)
 
-    def __len__(self) -> int:
-        return len(self.events)
-
     # ------------------------------------------------------------- perfetto
     def perfetto(self, num_workers: Optional[int] = None) -> Dict[str, Any]:
         """Render the typed events as a Chrome-trace document. Times map

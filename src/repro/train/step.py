@@ -254,24 +254,27 @@ class DistTrainer:
     # ------------------------------------------------------------- programs
     def _train_step(self, state: FlatState, batch, active):
         loss, grads = self._grads_and_loss(state.theta, batch)
-        grads = self._impl.gradient_transform(grads)
+        with jax.named_scope("grad_mean"):
+            grads = self._impl.gradient_transform(grads)
         center_new = state.center
         comm_delta = None
         if self._impl.uses_center:
             # center exchange (Alg. 2 lines 5-7), gated by the host scheduler,
             # directly on the resident buffers ([W, N] vs [N] center)
-            comm_delta, center_new = self._impl.center_step(
-                state.theta, state.center, active)
-        if self.fused_update and comm_delta is None:
-            # flat-plane fused NAG: velocity + parameter update in ONE pass
-            # (5 streams) instead of two per-bucket sweeps
-            p_new, v_new = self.fused_nag(
-                state.theta, state.opt.mu, grads,
-                lr_at(self.opt, state.step), jnp.float32(self.opt.momentum))
-        else:
-            p_new, v_new = self._nag(state.theta, state.opt.mu, grads, state.step)
-            if comm_delta is not None:
-                p_new = jax.tree.map(jnp.add, p_new, comm_delta)
+            with jax.named_scope("mix"):
+                comm_delta, center_new = self._impl.center_step(
+                    state.theta, state.center, active)
+        with jax.named_scope("update"):
+            if self.fused_update and comm_delta is None:
+                # flat-plane fused NAG: velocity + parameter update in ONE
+                # pass (5 streams) instead of two per-bucket sweeps
+                p_new, v_new = self.fused_nag(
+                    state.theta, state.opt.mu, grads,
+                    lr_at(self.opt, state.step), jnp.float32(self.opt.momentum))
+            else:
+                p_new, v_new = self._nag(state.theta, state.opt.mu, grads, state.step)
+                if comm_delta is not None:
+                    p_new = jax.tree.map(jnp.add, p_new, comm_delta)
         metrics = {"loss": jnp.mean(loss)}
         return state.replace(theta=p_new,
                              opt=OptState(state.opt.step + 1, v_new, {}),
@@ -307,9 +310,11 @@ class DistTrainer:
                 comm_new = comm.CommState(res_new)
             else:
                 exchanged = self._apply_gossip(state.theta, active, round_idx)
-            comm_delta = jax.tree.map(lambda a, b: a - b, exchanged, state.theta)
-            p_new, v_new = self._nag(state.theta, state.opt.mu, grads, state.step)
-            p_new = jax.tree.map(lambda p, d: p + d.astype(p.dtype), p_new, comm_delta)
+            with jax.named_scope("update"):
+                comm_delta = jax.tree.map(lambda a, b: a - b, exchanged, state.theta)
+                p_new, v_new = self._nag(state.theta, state.opt.mu, grads, state.step)
+                p_new = jax.tree.map(lambda p, d: p + d.astype(p.dtype), p_new,
+                                     comm_delta)
         metrics = {"loss": jnp.mean(loss)}
         return state.replace(theta=p_new,
                              opt=OptState(state.opt.step + 1, v_new, {}),

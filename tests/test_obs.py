@@ -114,7 +114,7 @@ def test_recording_run_is_bit_exact(engine):
     reproduces the non-recording trajectory bit-for-bit."""
     s0, _ = _run(_trainer(engine))
     rec = _trainer(engine, obs=_RECORDING)
-    assert rec.observer is not None and rec.observer.tracing
+    assert rec.observer is not None and rec.observer.trace is not None
     s1, _ = _run(rec)
     _assert_states_equal(s0, s1)
     rec.observer.flush()   # drain the one-step-deferred harvest

@@ -128,3 +128,19 @@ def test_aggregate_accuracy_beats_worst_worker():
         simple.mlp_logits(jax.tree.map(lambda p, i=i: p[i], state.params), x.reshape(-1, 10)),
         y.reshape(-1))) for i in range(4)]
     assert acc_agg >= min(accs) - 1e-6
+
+
+@pytest.mark.parametrize("method", ["allreduce", "elastic_gossip"])
+def test_step_compiles_once_from_a_fresh_state(method):
+    """init() commits the state to the device it lies on, so the first step
+    runs the program every later step runs: one compile of the step, not a
+    second one for the first call's uncommitted inputs."""
+    W = 4
+    tr = SimTrainer(mlp_loss, W, ProtocolConfig(method=method, comm_probability=0.5), OPT)
+    params = stacked(jax.random.PRNGKey(0), W)
+    state = tr.init(params, seed=0)
+    assert all(leaf.committed for leaf in jax.tree.leaves(state))
+    x, y = make_problem(W=W)
+    for _ in range(3):
+        state, _ = tr.step(state, x, y)
+    assert tr._step_fn._cache_size() == 1
