@@ -13,6 +13,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.common.config import MLAConfig, ModelConfig
+from repro.kernels import flash_attention as flash
 from repro.models.common import apply_rope, dense_init, split_tree, zeros_init
 
 PyTree = Any
@@ -117,13 +118,25 @@ def gqa_qkv(p, x, positions, theta):
     return q, k, v
 
 
+def _program_devices() -> int:
+    """Devices the program being traced may be partitioned over: the ambient
+    mesh's (as the dry-run sets it), else every device of the run."""
+    mesh = jax.sharding.get_abstract_mesh()
+    return mesh.size if mesh.axis_names else jax.device_count()
+
+
 @jax.named_scope("attention")
 def gqa_forward(p, x, cfg: ModelConfig, *, window=0, positions=None, chunk: int = 1024):
     B, S, _ = x.shape
     positions = jnp.arange(S) if positions is None else positions
     q, k, v = gqa_qkv(p, x, positions, cfg.rope_theta)
-    o = chunked_attention(q, k, v, causal=True, window=window,
-                          logit_softcap=cfg.attn_logit_softcap, chunk=min(chunk, S))
+    if flash.fits(backend=jax.default_backend(), devices=_program_devices(), seq_q=S,
+                  seq_kv=S, groups=q.shape[2] // k.shape[2], head_dim=q.shape[-1],
+                  window=window, softcap=cfg.attn_logit_softcap):
+        o = flash.causal_attention(q, k, v)
+    else:
+        o = chunked_attention(q, k, v, causal=True, window=window,
+                              logit_softcap=cfg.attn_logit_softcap, chunk=min(chunk, S))
     return jnp.einsum("bshk,hkd->bsd", o, p["wo"].astype(x.dtype)), (k, v)
 
 

@@ -219,14 +219,15 @@ def lm_logits(params, cfg: ModelConfig, x):
 # ---------------------------------------------------------------------------
 
 def _scan_segment(seg: Segment, seg_params, x, cfg: ModelConfig, cond):
-    windows = (jnp.array(seg.windows, jnp.int32) if seg.windows is not None
-               else jnp.zeros((seg.count,), jnp.int32))
+    # per-layer windows ride the scan; without them every layer's window is
+    # the static 0, which attention's kernel dispatch can see
+    windows = None if seg.windows is None else jnp.array(seg.windows, jnp.int32)
 
     def body(carry, layer):
         xc, aux = carry
         p, w = layer
         y, a = blocks.block_forward(seg.kind, p, xc, cfg, use_moe=seg.use_moe,
-                                    window=w, cond=cond)
+                                    window=0 if w is None else w, cond=cond)
         return (y, aux + a), None
 
     if cfg.remat:

@@ -145,3 +145,56 @@ def test_ops_dispatch_ref_path_matches_kernel():
     b = ops.flash_attention(q, k, v, use_kernel=True, interpret=True,
                             block_q=32, block_k=32)
     np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=2e-5, atol=2e-5)
+
+
+# ---------------------------------------------------------------------------
+# causal training kernel (forward with log-sum-exp, dq and dk/dv backward)
+# ---------------------------------------------------------------------------
+
+def _vmap_over_workers(f):
+    """f over a leading worker dim of 2, as the sim engine stacks replicas."""
+    def g(q, k, v):
+        rep = lambda t: jnp.stack([t, 0.5 * t[:, ::-1]])
+        return jax.vmap(f)(rep(q), rep(k), rep(v))[1]
+    return g
+
+
+@pytest.mark.parametrize("H,Hkv,S,bq,bk,wrap", [
+    (4, 2, 128, 128, 128, "plain"),          # GQA, one block
+    (4, 2, 384, 128, 128, "vmap+remat"),     # GQA, 3x3 blocks: causal skipping
+    (2, 2, 256, 128, 128, "vmap"),           # MHA
+    (4, 2, 512, 128, 256, "remat"),          # block_q < block_k
+    (2, 2, 512, 256, 128, "plain"),          # MHA, block_q > block_k
+    (6, 1, 2048, 1024, 1024, "plain"),       # MQA, the 6 heads in two groups of 3
+])
+def test_causal_train_kernel_matches_chunked(H, Hkv, S, bq, bk, wrap):
+    """Output and d/dq, d/dk, d/dv of the training kernel (interpret mode,
+    f32) against chunked_attention, under the transforms the sim step uses."""
+    from repro.kernels.flash_attention import causal_attention
+    from repro.models.attention import chunked_attention
+    B, hd = 1, 128
+    ks = jax.random.split(jax.random.PRNGKey(S + H), 4)
+    q = jax.random.normal(ks[0], (B, S, H, hd))
+    k = jax.random.normal(ks[1], (B, S, Hkv, hd))
+    v = jax.random.normal(ks[2], (B, S, Hkv, hd))
+    ct = jax.random.normal(ks[3], (B, S, H, hd))
+    kern = lambda q, k, v: causal_attention(q, k, v, block_q=bq, block_k=bk,
+                                            interpret=True)
+    chunk = lambda q, k, v: chunked_attention(q, k, v, causal=True, chunk=S)
+    outs = []
+    for f in (kern, chunk):
+        if "remat" in wrap:
+            f = jax.checkpoint(f)
+        if "vmap" in wrap:
+            f = _vmap_over_workers(f)
+        o, vjp = jax.vjp(f, q, k, v)
+        outs.append((o,) + vjp(ct))
+    for a, b in zip(*outs):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=2e-5, atol=2e-5)
+
+
+def test_causal_train_kernel_refuses_blocks_that_do_not_tile():
+    from repro.kernels.flash_attention import causal_attention
+    q = jnp.zeros((1, 192, 2, 128))
+    with pytest.raises(ValueError):
+        causal_attention(q, q, q, block_q=128, block_k=128, interpret=True)

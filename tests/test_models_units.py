@@ -183,3 +183,97 @@ def test_router_stats_load_sums_to_one():
     x = jax.random.normal(KEY, (2, 32, 16))
     stats = moe_mod.router_stats(p, x, cfg)
     np.testing.assert_allclose(float(stats["expert_load"].sum()), 1.0, rtol=1e-5)
+
+
+# ---------------------------------------------------------------------------
+# which attention calls take the training flash kernel
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("kw,takes", [
+    (dict(), True),
+    (dict(backend="cpu"), False),
+    (dict(window=jnp.zeros((), jnp.int32)), False),   # a traced window
+    (dict(window=4096), False),
+    (dict(softcap=50.0), False),
+    (dict(head_dim=64), False),
+    (dict(seq_q=1, seq_kv=1024), False),
+    (dict(seq_q=1000, seq_kv=1000), False),
+    (dict(seq_q=128, seq_kv=128), True),
+    (dict(seq_q=4096, seq_kv=4096, head_dim=256), True),
+    (dict(devices=4), False),                         # a program over four chips
+    (dict(groups=48), True),                          # granite_20b: 48 heads, 1 kv head
+    (dict(groups=1, head_dim=1024), False),           # one head's block is too large
+])
+def test_flash_dispatch_rule(kw, takes):
+    from repro.kernels import flash_attention as flash
+    args = dict(backend="tpu", devices=1, seq_q=1024, seq_kv=1024, groups=4,
+                head_dim=128, window=0, softcap=0.0)
+    assert flash.fits(**{**args, **kw}) is takes
+
+
+def _benchmark_granite_cut1():
+    import json
+    from pathlib import Path
+    import sys
+    chip = Path(__file__).resolve().parents[1] / "benchmarks" / "chip"
+    sys.path.insert(0, str(chip))
+    import harness
+    return harness.model_config(json.loads((chip / "configs" / "granite_3_8b.cut1.json")
+                                           .read_text()))
+
+
+# config -> what the TPU would run for each traced self-attention call of the
+# training forward at S=1024: True the kernel, False chunked_attention; an
+# empty set means no gqa_forward call (MLA, cross-attention only, no attention)
+TAKES_KERNEL = {
+    "tinyllama_1_1b": {False},          # head_dim 64
+    "deepseek_v2_lite_16b": set(),      # MLA
+    "xlstm_125m": set(),                # no attention
+    "granite_20b": {True},
+    "grok_1_314b": {True},
+    "granite_3_8b": {True},
+    "musicgen_large": {False},          # head_dim 64
+    "gemma2_9b": {False},               # per-layer (traced) windows, softcap
+    "llama_3_2_vision_11b": {True},     # self-attention; cross-attention stays jnp
+    "zamba2_2_7b": {False},             # head_dim 80
+    "granite_3_8b.cut1": {True},        # the benchmark's configuration
+}
+
+
+@pytest.mark.parametrize("name", sorted(TAKES_KERNEL))
+def test_which_training_attention_takes_the_kernel(name, monkeypatch):
+    """Trace each full configuration's training forward (shapes only) with
+    the dispatch rule asked as if on one TPU chip, and record its answers."""
+    from repro.configs import ARCH_IDS, get_config
+    from repro.kernels import flash_attention as flash
+    from repro.models import transformer as tr
+    assert set(ARCH_IDS) | {"granite_3_8b.cut1"} == set(TAKES_KERNEL)
+    cfg = _benchmark_granite_cut1() if name == "granite_3_8b.cut1" else get_config(name)
+    seen, fits = [], flash.fits
+
+    def spy(**kw):
+        seen.append(fits(**{**kw, "backend": "tpu", "devices": 1}))
+        return False                     # the CPU still runs chunked_attention
+    monkeypatch.setattr(flash, "fits", spy)
+    params, _ = tr.abstract_lm(cfg)
+    S = 1024
+    if cfg.audio is not None:
+        tokens = jax.ShapeDtypeStruct((1, cfg.audio.num_codebooks, S), jnp.int32)
+        cond = jax.ShapeDtypeStruct((1, cfg.audio.num_cond_tokens, cfg.d_model), jnp.float32)
+    else:
+        tokens = jax.ShapeDtypeStruct((1, S), jnp.int32)
+        cond = (jax.ShapeDtypeStruct((1, cfg.vlm.num_image_tokens, cfg.vlm.image_embed_dim),
+                                     jnp.float32) if cfg.vlm is not None else None)
+    jax.eval_shape(lambda p, t, c: tr.forward(p, cfg, t, c), params, tokens, cond)
+    assert set(seen) == TAKES_KERNEL[name]
+
+
+def test_gqa_forward_off_the_tpu_runs_chunked_attention():
+    from repro.models.attention import gqa_forward, init_gqa
+    cfg = ModelConfig(name="t", arch_type="dense", num_layers=1, d_model=256,
+                      num_heads=2, num_kv_heads=1, d_ff=512, vocab_size=64)
+    p, _ = init_gqa(KEY, cfg)
+    x = jnp.ones((1, 128, 256))
+    jaxpr = str(jax.make_jaxpr(lambda p, x: gqa_forward(p, x, cfg)[0])(p, x))
+    assert jax.default_backend() != "tpu"
+    assert "pallas_call" not in jaxpr and "scan" in jaxpr

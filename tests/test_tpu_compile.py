@@ -2,7 +2,9 @@
 
 Nothing here runs on a chip: the TPU compiler builds each kernel for a
 described (not attached) ``v5e:2x2`` topology, at the width of the
-xlstm_125m flat plane. Interpret-mode tests cannot see what this catches —
+xlstm_125m flat plane, the training flash attention kernels at the granite
+cell's, granite_20b's and grok's attention shapes, and the dist train step
+over one and four chips. Interpret-mode tests cannot see what this catches —
 block shapes the chip's tiling refuses, VMEM overuse, a relayout copy of the
 plane. The topology is described inside a fixture, never at import, so every
 test worker collects the same tests and only the one running this file loads
@@ -102,3 +104,76 @@ def test_kernel_compiles_for_v5e(kernel, W, one_chip, plane_n):
     assert "tpu_custom_call" in compiled.as_text()
     # the kernel reads the plane where it lies: no plane-sized relayout copy
     assert compiled.memory_analysis().temp_size_in_bytes < plane_n * 4
+
+
+# (W, B, S, H, Hkv): the granite cell's attention, granite_20b's (48 query heads
+# on one kv head, taken in groups) and grok's (6 query heads per kv head) at 4k
+FLASH_SHAPES = {"granite_3_8b.cut1": (2, 4, 1024, 32, 8),
+                "granite_20b": (1, 1, 4096, 48, 1),
+                "grok_1_314b": (1, 1, 4096, 48, 8)}
+
+
+@pytest.mark.parametrize("shape", sorted(FLASH_SHAPES))
+def test_flash_train_kernels_compile_for_v5e(shape, one_chip):
+    """The training flash kernels (forward with log-sum-exp, dq, dk/dv), W
+    replicas vmapped, heads of 128, f32, within their scoped VMEM."""
+    from repro.kernels.flash_attention import causal_attention
+    W, B, S, H, Hkv = FLASH_SHAPES[shape]
+    hd = 128
+
+    def sds(heads):
+        return jax.ShapeDtypeStruct((W, B, S, heads, hd), jnp.float32, sharding=one_chip)
+
+    def loss(q, k, v, ct):
+        return jnp.sum(jax.vmap(causal_attention)(q, k, v) * ct)
+
+    compiled = jax.jit(jax.grad(loss, (0, 1, 2))).lower(
+        sds(H), sds(Hkv), sds(Hkv), sds(H)).compile()
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 3
+    for name in ("flash_fwd_lse", "flash_dq", "flash_dkv"):
+        assert name in text
+
+
+def _dist_train_step(devices):
+    """The dist engine's gradient-mean train step compiled over ``devices``,
+    one replica on each, under the ambient mesh as the dry-run compiles it:
+    one dense layer with two 128-lane query heads on one kv head, S=256."""
+    import numpy as np
+    from jax.sharding import AxisType, Mesh
+    from repro.common.config import (MeshConfig, ModelConfig, OptimizerConfig,
+                                     TrainConfig)
+    from repro.launch.specs import make_trainer
+    n = len(devices)
+    mesh = Mesh(np.array(devices).reshape(1, n, 1, 1), ("pod", "worker", "fsdp", "model"),
+                axis_types=(AxisType.Auto,) * 4)
+    cfg = ModelConfig(name="t", arch_type="dense", num_layers=1, d_model=256,
+                      num_heads=2, num_kv_heads=1, d_ff=512, vocab_size=256)
+    train_cfg = TrainConfig(protocol=ProtocolConfig(method="allreduce"),
+                            optimizer=OptimizerConfig(name="nag", learning_rate=1e-3,
+                                                      momentum=0.9))
+    trainer = make_trainer(mesh, MeshConfig(data=n, model=1, workers_per_pod=n), cfg,
+                           1, train_cfg)
+    trainer.set_shape(2 * n, 256)
+    with jax.set_mesh(mesh):
+        lowered = trainer.jit_train_step().lower(
+            trainer.state_shapes(), trainer.batch_shapes(),
+            jax.ShapeDtypeStruct((), jnp.float32))
+    return lowered.compile().as_text()
+
+
+@pytest.mark.parametrize("chips", [1, 4])
+def test_dist_step_attention_on_v5e(chips, topo, no_persistent_cache, monkeypatch):
+    """Over one chip the dist step's attention takes the training kernels.
+    Over four, where GSPMD partitions the step (the replicas' dim is sharded)
+    and JAX refuses to lower a pallas_call it would have to partition, the
+    step keeps chunked_attention and gathers nothing."""
+    from repro.kernels import flash_attention as flash
+    fits = flash.fits
+    monkeypatch.setattr(flash, "fits", lambda **kw: fits(**{**kw, "backend": "tpu"}))
+    text = _dist_train_step(topo.devices[:chips])
+    kernels = [n for n in ("flash_fwd_lse", "flash_dq", "flash_dkv") if n in text]
+    if chips == 1:
+        assert len(kernels) == 3
+    else:
+        assert kernels == [] and "all-gather" not in text
