@@ -3,9 +3,14 @@
 A cell is a workload of ``BENCHMARK.json``: a model configuration
 (``configs/<config>.json``) under a traffic mix (``traffic/<traffic>.json``),
 with the limits of its comparison in ``limits/<workload>.json`` and one reader
-per per-layer metric in ``metrics/<name>.py``. Everything is found by the names
-``BENCHMARK.json`` gives, so a cell, a configuration or a metric is added with
-new files and new entries only.
+per per-layer metric in ``metrics/<name>.py``. A configuration names its
+model family's plain reference, ``refs/<reference>.py``, which initialises the
+weights, computes the loss and counts the family's operations per token
+(``forward_flops``, read by ``flops.py``). Everything is found by the names
+``BENCHMARK.json`` and the configuration give, so a cell, a configuration, a
+model family or a metric is added with new files and new entries only. A
+reader gets a ``ctx`` from :func:`reader_context`, the cell's configuration and
+traffic among it.
 
 The run:
 
@@ -286,6 +291,27 @@ def reference(cell, run, devices, dtype=None, fault: str = "",
                     for th in r_thetas], 1)}
 
 
+def reader_context(cell, trace_path: str, steps: int, chips: int, peak: dict):
+    """(trace, ctx): the trace of a run's window at ``trace_path`` and what
+    the per-layer readers see of the run: its first ``chips`` devices' ops
+    clipped to the ``window`` span and their busy seconds, the window's length,
+    the steps and tokens in it, the chips' peaks, the required operations per
+    token, the cell's configuration and traffic, and the trace's path."""
+    import devtrace
+    import flops
+    tr = devtrace.load(trace_path)
+    lo, hi = devtrace.window(tr)
+    ops = {d: devtrace.clip(o, lo, hi) for d, o in tr.ops.items() if d < chips}
+    t = cell.traffic
+    return tr, SimpleNamespace(
+        ops=ops, window_s=(hi - lo) / 1e9,
+        busy_s=[devtrace.busy_ns(o) / 1e9 for o in ops.values()], steps=steps,
+        tokens=steps * t["workers"] * t["per_worker_batch"] * t["seq"],
+        chips=chips, peak=peak,
+        flops_per_token=flops.train_flops_per_token(cell.config, t["seq"]),
+        config=cell.config, traffic=t, trace_path=trace_path)
+
+
 def run(args, root: Path = CHECKOUT, t_start: float = None) -> dict:
     """One run; returns the result object (the last line of stdout)."""
     t_start = time.perf_counter() if t_start is None else t_start
@@ -295,11 +321,10 @@ def run(args, root: Path = CHECKOUT, t_start: float = None) -> dict:
     devices, peak = require_chips(cell)
     import compare
     import devtrace
-    import flops
 
     cache_dir = enable_cache()
     clock = Clock()
-    cfg, t = cell.config, cell.traffic
+    t = cell.traffic
     W, B, S = t["workers"], t["per_worker_batch"], t["seq"]
     prep = prepare(cell, args.seed, devices)
     trainer, state, pool = prep.trainer, prep.state, prep.pool
@@ -365,15 +390,10 @@ def run(args, root: Path = CHECKOUT, t_start: float = None) -> dict:
           flush=True)
     result = {"correct": correct, "attempted": steps, "failed": failed}
     if args.trace:
-        tr_ = devtrace.load(trace_dir)
+        tr_, ctx = reader_context(cell, devtrace.xplane_file(trace_dir), steps,
+                                  len(devices), peak)
+        ops, busy = ctx.ops, ctx.busy_s
         lo, hi = devtrace.window(tr_)
-        ops = {d: devtrace.clip(o, lo, hi) for d, o in tr_.ops.items()}
-        ops = {d: o for d, o in ops.items() if d < len(devices)}
-        busy = [devtrace.busy_ns(o) / 1e9 for o in ops.values()]
-        ctx = SimpleNamespace(
-            ops=ops, window_s=(hi - lo) / 1e9, busy_s=busy, steps=steps,
-            tokens=steps * tokens_per_step, chips=len(devices), peak=peak,
-            flops_per_token=flops.train_flops_per_token(cfg, S))
         metrics = {}
         for entry, mod in cell.metrics:
             v = mod.read(ctx)

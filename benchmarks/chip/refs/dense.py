@@ -75,6 +75,22 @@ def _attention(p, x, cfg):
     return jnp.einsum("bshk,hkd->bsd", o, p["wo"])
 
 
+def forward_flops(cfg, seq):
+    """Forward operations per token of the whole model at sequence length
+    ``seq``, by term (the rule is ``flops.py``'s): ``weights``, 2 x the
+    matmul weights a token passes in every layer (q, k, v, o and the SwiGLU's
+    three); ``attention``, q.k and p.v of every layer over the mean causal
+    context; ``head``, the LM head, tied or not."""
+    d, V, F = cfg["d_model"], cfg["vocab_size"], cfg["d_ff"]
+    H, Hkv, hd = cfg["num_heads"], cfg["num_kv_heads"], _head_dim(cfg)
+    L = cfg["num_layers"]
+    ctx = (seq + 1) / 2
+    weights = d * H * hd + 2 * d * Hkv * hd + H * hd * d + 3 * d * F
+    return {"weights": L * 2.0 * weights,
+            "attention": L * 2.0 * 2 * H * hd * ctx,
+            "head": 2.0 * d * V}
+
+
 def _ffn(p, x):
     return (jax.nn.silu(x @ p["w_gate"]) * (x @ p["w_up"])) @ p["w_down"]
 

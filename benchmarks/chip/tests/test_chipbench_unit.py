@@ -81,20 +81,32 @@ def test_recorded_tpu_trace_reduces():
 
 # ------------------------------------------------------------------- flops
 def test_dense_flops_by_hand():
+    """The dense family's count lives with its reference, by term."""
     cfg = {"reference": "dense", "num_layers": 2, "d_model": 8, "num_heads": 4,
            "num_kv_heads": 2, "d_ff": 12, "vocab_size": 10}
     # head_dim 2; seq 5 -> mean context 3
     weights = 8 * 8 + 2 * 8 * 4 + 8 * 8 + 3 * 8 * 12             # 480
     attention = 2 * 2 * 4 * 2 * 3
-    forward = 2 * (2 * weights + attention) + 2 * 8 * 10
-    assert flops.train_flops_per_token(cfg, 5) == 3 * forward
+    terms = {"weights": 2 * 2 * weights, "attention": 2 * attention,
+             "head": 2 * 8 * 10}
+    assert harness.reference_model(cfg).forward_flops(cfg, 5) == terms
+    assert flops.forward_terms(cfg, 5) == terms
+    assert flops.train_flops_per_token(cfg, 5) == 3 * sum(terms.values())
 
 
 def test_published_config_flops():
-    """The configuration at its cell's sequence: 1.37 GFLOP per token trained,
-    as PERF.md states."""
+    """The configuration at its cell's sequence: 1,371,561,984 operations per
+    token trained, the count ``step_mfu`` has read since the benchmark began."""
     g = json.loads((CHIP / "configs" / "granite_3_8b.cut1.json").read_text())
-    assert 1.3e9 < flops.train_flops_per_token(g, 1024) < 1.45e9
+    assert flops.train_flops_per_token(g, 1024) == 1_371_561_984
+    assert flops.forward_terms(g, 1024) == {
+        "weights": 398_458_880, "attention": 8_396_800, "head": 50_331_648}
+
+
+def test_flops_names_no_family():
+    """A family's count is found through its reference alone."""
+    source = (CHIP / "flops.py").read_text()
+    assert "dense" not in source and "FORWARD" not in source
 
 
 # ------------------------------------------------------------------- cells

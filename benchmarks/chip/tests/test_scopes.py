@@ -3,7 +3,9 @@ that read it): the protobuf decoding against ``jax.profiler.ProfileData``,
 the self-time partition and the scope of an op by hand, and every reader on
 a trace recorded on a TPU v5e from the scoped program
 (``data/scoped_tpu_trace.xplane.pb``, made by ``make_scoped_trace.py``)
-against sums of the same events computed here by brute force."""
+against sums of the same events computed here by brute force, and every
+reader of the granite cell alike whether the harness gives it the trace's
+path or it searches for the trace."""
 from __future__ import annotations
 
 import shutil
@@ -14,9 +16,11 @@ from pathlib import Path
 import pytest
 
 CHIP = Path(__file__).resolve().parents[1]
+ROOT = CHIP.parents[1]
 sys.path.insert(0, str(CHIP))
 
 import devtrace  # noqa: E402
+import flops     # noqa: E402
 import harness   # noqa: E402
 import scopes    # noqa: E402
 # the temporary one-cell benchmark and the CPU standing in for the chip
@@ -197,16 +201,20 @@ def test_trace_is_found_where_the_harness_writes_it(tmp_path, monkeypatch):
 
 
 def test_traced_run_finds_its_trace_for_the_readers(bench, monkeypatch):
-    """The harness gives the readers no trace path: a reader of ``scopes.py``
-    finds the trace the harness wrote and reads the ``train_step`` spans of
-    the window (the CPU trace has no TPU plane, so no layer reads)."""
+    """The harness gives the readers the trace it wrote, the cell's
+    configuration and its traffic: a reader of ``scopes.py`` reads the
+    ``train_step`` spans of the window (the CPU trace has no TPU plane, so no
+    layer reads)."""
     load = harness.load_cell
+    seen = []
 
     def with_readers(*a, **kw):
         cell = load(*a, **kw)
         for name in ("host_step_ms", "ffn_ms"):
             cell.metrics.append(({"name": name, "unit": "ms"}, harness._module(
                 CHIP / "metrics" / f"{name}.py", f"metric_{name}")))
+        cell.metrics.append(({"name": "seen", "unit": "x"},
+                             types.SimpleNamespace(read=seen.append)))
         return cell
 
     monkeypatch.setattr(harness, "load_cell", with_readers)
@@ -215,4 +223,33 @@ def test_traced_run_finds_its_trace_for_the_readers(bench, monkeypatch):
     r = harness.run(args, root=bench)
     assert r["correct"], r["checks"]
     assert 0 < r["metrics"]["host_step_ms"]["value"] < 1e3
-    assert "ffn_ms" not in r["metrics"]
+    assert "ffn_ms" not in r["metrics"] and "seen" not in r["metrics"]
+    ctx, = seen
+    assert ctx.trace_path.endswith(".xplane.pb") and "chip_trace_" in ctx.trace_path
+    assert ctx.config["d_model"] == 64 and ctx.traffic["seq"] == 32
+    assert ctx.flops_per_token == flops.train_flops_per_token(ctx.config, 32)
+
+
+GRANITE = "granite_3_8b.cut1.sim_w2.allreduce"
+EXISTING = ("device_idle_share", "step_mfu", "attention_kernel_ms") + METRICS
+
+
+@pytest.mark.parametrize("name", EXISTING + ("attention_kernel_roofline",))
+@pytest.mark.parametrize("path", [SCOPED, UNSCOPED], ids=["scoped", "unscoped"])
+def test_every_reader_reads_alike_with_and_without_the_path(name, path, tmp_path,
+                                                            monkeypatch):
+    """The granite cell's readers on a recorded trace, through the harness's
+    own ``reader_context``: the given path and the search of the temporary
+    directory find the same trace, so each reader reads the same."""
+    cell = harness.load_cell(GRANITE, ROOT)
+    _, ctx = harness.reader_context(cell, str(path), STEPS, 1,
+                                    cell.peaks["TPU v5 lite"])
+    want = _reader(name).read(ctx)
+    monkeypatch.setattr(scopes.tempfile, "tempdir", str(tmp_path))
+    where = tmp_path / "chip_trace_x" / "plugins" / "profile" / "run"
+    where.mkdir(parents=True)
+    shutil.copy(path, where / "host.xplane.pb")
+    del ctx.trace_path
+    assert _reader(name).read(ctx) == want
+    if name in LAYERS or name == "unscoped_share":
+        assert (want is None) == (path == UNSCOPED)
