@@ -278,14 +278,13 @@ def reference(cell, run, devices, dtype=None, fault: str = "",
     with jax.default_matmul_precision(precision):
         batches = [tuple(jnp.asarray(x) for x in run.pool_np[s])
                    for s in range(CHECK_STEPS)]
+        p0 = run.make_params(run.key)     # train leaves it as it is
         r_losses, r_grads, r_thetas = algorithm.train(
-            run.model, cell.config, cell.traffic, run.make_params(run.key),
-            batches, run.seeds.trainer, steps=CHECK_STEPS, devices=devices,
+            run.model, cell.config, cell.traffic, p0, batches,
+            run.seeds.trainer, steps=CHECK_STEPS, devices=devices,
             dtype=dtype or jnp.float32, fault=fault)
-        p0 = run.make_params(run.key)
         return {"losses": r_losses, "names": compare.leaf_names(p0),
-                "grad": np.stack([np.asarray(compare.leaf_norms(g))
-                                  for g in r_grads], 1),
+                "grad": np.stack(r_grads, 1),
                 "change": np.stack([np.asarray(compare.leaf_norms(
                     jax.tree.map(lambda a, b: a - b, th, p0)))
                     for th in r_thetas], 1)}

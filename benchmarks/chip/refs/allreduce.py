@@ -8,6 +8,21 @@ gradients, and each steps with it:
     v_i'    = mu * v_i - lr * g
     theta_i' = theta_i - lr * g + mu * v_i'
 
+Since every replica starts from the same weights and steps with the same g,
+the W replicas are one tree at every step, exactly; the reference holds that
+one tree and one velocity. It takes the W gradients one worker at a time at
+the current weights, each added to a running sum in one buffer and dropped
+before the next is taken, and divides the sum by W: ``((g_1 + g_2) + ...) / W``,
+the order of Python's ``sum(gs) / W``, so the mean is the same number as the
+one a W-replica form computes.
+
+Memory: with P the size of one replica in the computing dtype, it holds the
+weights, the velocity, the running sum and one worker's gradient, 4 P, and at
+most about 6 P with what the runtime keeps, above the weights it is handed,
+whatever W is; plus one worker's activations at ``highest`` precision. With the
+gradient mean left out (``fault="nomean"``, calibration only) the replicas
+differ, and it holds W weights and W velocities, 2 W P, and one gradient.
+
 It imports nothing of the system under test and takes nothing it made: the
 weights come from the reference model's own ``init`` on the seed. The harness
 finds it by the traffic's ``method``; another algorithm is another file of
@@ -15,10 +30,14 @@ finds it by the traffic's ``method``; another algorithm is another file of
 """
 from __future__ import annotations
 
+from functools import partial
+
 import numpy as np
 
 import jax
 import jax.numpy as jnp
+
+from compare import leaf_norms
 
 
 def train(model, cfg: dict, traffic: dict, params0, batches, seed: int, *,
@@ -27,11 +46,14 @@ def train(model, cfg: dict, traffic: dict, params0, batches, seed: int, *,
     (one ``(tokens, labels)`` pair of ``[W, B, S]`` arrays per step).
 
     Returns ``(losses, grads, thetas)``: the mean loss over workers at each
-    step, each worker's first gradient (as its optimizer gets it), and each
-    worker's parameters after the last step (float32 trees). ``fault`` plants
-    a fault for calibration: ``"half"`` takes the loss over the first half of
-    each worker's rows only, ``"nomean"`` leaves the gradient mean out.
-    ``seed`` is unused: the algorithm draws nothing.
+    step, the leaf norms (``compare.leaf_norms``, float32 ``[n_leaves]``) of
+    each worker's first gradient as its optimizer gets it, and each worker's
+    parameters after the last step (float32 trees). Where the mean is taken
+    the W entries of ``grads`` and of ``thetas`` are one and the same object.
+    ``params0`` is left as it was handed in. ``fault`` plants a fault for
+    calibration: ``"half"`` takes the loss over the first half of each
+    worker's rows only, ``"nomean"`` leaves the gradient mean out. ``seed`` is
+    unused: the algorithm draws nothing.
     """
     del seed
     W = traffic["workers"]
@@ -45,31 +67,58 @@ def train(model, cfg: dict, traffic: dict, params0, batches, seed: int, *,
         return jax.value_and_grad(model.loss)(theta, cfg, tokens, labels)
 
     grad_fn = jax.jit(value_and_grad)
-    cast = jax.jit(lambda t: jax.tree.map(lambda x: x.astype(dtype), t))
-    theta = [cast(params0) for _ in range(W)]
-    vel = [jax.tree.map(jnp.zeros_like, t) for t in theta]
 
-    @jax.jit
+    def grad(theta, t, w):
+        loss, g = grad_fn(theta, jax.device_put(batches[t][0][w], dev),
+                          jax.device_put(batches[t][1][w], dev))
+        return float(loss), g
+
+    # a copy even in float32: the update donates what it steps, and params0
+    # stays the caller's
+    fresh = jax.jit(lambda t: jax.tree.map(lambda x: jnp.copy(x.astype(dtype)), t))
+    add = jax.jit(lambda a, g: jax.tree.map(jnp.add, a, g), donate_argnums=0)
+    divide = jax.jit(lambda a: jax.tree.map(lambda x: x / W, a), donate_argnums=0)
+
+    @partial(jax.jit, donate_argnums=(0, 1))
     def update(th, v, g):
         v_new = jax.tree.map(lambda v, g: mu * v - lr * g, v, g)
         th_new = jax.tree.map(lambda x, g, v: x - lr * g + mu * v, th, g, v_new)
         return th_new, v_new
 
-    mean = jax.jit(lambda *gs: jax.tree.map(lambda *x: sum(x) / W, *gs))
-    losses, first_grads = [], None
+    def norms(g):
+        return np.asarray(leaf_norms(g))
+
+    # every ``del g`` drops a gradient before the next one is taken
+    losses, first = [], []
+    if fault == "nomean":
+        theta = [fresh(params0) for _ in range(W)]
+        vel = [jax.tree.map(jnp.zeros_like, th) for th in theta]
+        for t in range(steps):
+            step_losses = []
+            for w in range(W):
+                loss, g = grad(theta[w], t, w)
+                step_losses.append(loss)
+                if t == 0:
+                    first.append(norms(g))
+                theta[w], vel[w] = update(theta[w], vel[w], g)
+                del g
+            losses.append(float(np.mean(step_losses)))
+        thetas = [jax.tree.map(lambda x: x.astype(jnp.float32), th) for th in theta]
+        return losses, first, thetas
+
+    theta = fresh(params0)
+    vel = jax.tree.map(jnp.zeros_like, theta)
     for t in range(steps):
-        out = [grad_fn(theta[w], jax.device_put(batches[t][0][w], dev),
-                       jax.device_put(batches[t][1][w], dev))
-               for w in range(W)]
-        losses.append(float(np.mean([float(l) for l, _ in out])))
-        grads = [g for _, g in out]
-        if fault != "nomean":
-            grads = [mean(*grads)] * W
+        step_losses, total = [], None
+        for w in range(W):
+            loss, g = grad(theta, t, w)
+            step_losses.append(loss)
+            total = g if total is None else add(total, g)
+            del g
+        losses.append(float(np.mean(step_losses)))
+        g = divide(total)
         if t == 0:
-            first_grads = [jax.tree.map(lambda g: g.astype(jnp.float32), g)
-                           for g in grads]
-        new = [update(theta[w], vel[w], grads[w]) for w in range(W)]
-        theta = [n[0] for n in new]
-        vel = [n[1] for n in new]
-    thetas = [jax.tree.map(lambda x: x.astype(jnp.float32), t) for t in theta]
-    return losses, first_grads, thetas
+            first = [norms(g)] * W
+        theta, vel = update(theta, vel, g)
+        del g
+    return losses, first, [jax.tree.map(lambda x: x.astype(jnp.float32), theta)] * W
